@@ -198,7 +198,8 @@ def bench_sharded_decode(results: dict, n: int, d: int, D: int, K: int,
         "mgqe_decode", [(sel, artifact["centroids"])],
         backend=backend).values()))
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ("data", "model"))
     emb_sharded = Embedding(cfg)
     art_sharded = shard_quantized_artifact(artifact, cfg, mesh)
     with mesh:
@@ -937,6 +938,8 @@ def bench_dpq_assign(results: dict, d: int, D: int, K: int, b: int):
 
 def main(out_json: str = "BENCH_kernels.json", quick: bool = False,
          scale_rows: int = 0, scale_backend: str = None):
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     print("== kernel micro-bench (dispatch-layer paths + byte accounting) ==")
     n, d, D, K = (100_000 if quick else 1_000_000), 64, 8, 256
     results = {

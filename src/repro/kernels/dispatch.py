@@ -25,10 +25,11 @@ in precedence order:
      :func:`use_backend`
   4. ``auto``: ``pallas`` when a TPU is attached, else ``xla``
 
-``auto`` is also re-resolved *per choice*: asking for ``pallas`` with
-no TPU present silently falls back to ``xla`` (compiling a real Mosaic
-kernel without TPU hardware would just crash), while ``interpret``
-always honours the request — that is the whole point of interpret mode.
+Only ``auto`` looks at the hardware.  A concrete request is honoured
+or refused, never swapped: asking for ``pallas`` with no TPU attached
+raises (a measurement that asked for the kernel must not silently time
+the XLA reference instead), while ``interpret`` runs anywhere — that is
+the whole point of interpret mode.
 
 Registration is done by each kernel package's ``ops.py`` at import
 time; :func:`dispatch` lazily imports ``repro.kernels`` so the registry
@@ -127,8 +128,8 @@ def resolve_backend(backend: Optional[str] = None) -> str:
     """Resolve a backend request to a concrete one of pallas|xla|interpret.
 
     Precedence: explicit arg > $REPRO_KERNEL_BACKEND > process default
-    > auto.  ``auto`` (and an unfulfillable ``pallas`` request) resolve
-    to ``pallas`` on TPU and ``xla`` elsewhere.
+    > auto.  ``auto`` resolves to ``pallas`` on TPU and ``xla``
+    elsewhere; ``pallas`` without a TPU raises RuntimeError.
     """
     if backend == "auto":
         backend = None          # "auto" carries no preference
@@ -139,10 +140,10 @@ def resolve_backend(backend: Optional[str] = None) -> str:
     if choice == "auto":
         return "pallas" if _on_tpu() else "xla"
     if choice == "pallas" and not _on_tpu():
-        # a compiled Mosaic kernel needs real TPU hardware; interpret
-        # mode must be asked for explicitly (it is orders of magnitude
-        # slower than the XLA reference path).
-        return "xla"
+        raise RuntimeError(
+            f"kernel backend 'pallas' needs a TPU, but JAX's backend is "
+            f"{jax.default_backend()!r}; ask for 'auto', 'xla' or "
+            f"'interpret' instead")
     return choice
 
 

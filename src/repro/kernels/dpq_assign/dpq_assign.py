@@ -10,11 +10,18 @@ MGQE's tier rule rides along as a per-item mask: slots k >= k_limit[b]
 get +inf before the argmin — the masked single-pass lookup that
 replaces the paper's dynamic group-split (DESIGN.md §3).
 
-Block layout: grid over (B blocks, D).  Per step: e block (Bblk, 1, S),
-centroid block (1, K, S) — both VMEM; distances (Bblk, K) never leave
-VMEM; only the int32 codes (Bblk, 1) are written back.  This is the
-fusion win: XLA's unfused path would round-trip the (B, D, K) distance
-tensor through HBM.
+Block layout: rows on LANES.  The wrapper hands the kernel the
+subvectors transposed, (D*S, B), so a grid step over B takes a
+(D*S, Bblk) block; per subspace j the distances are one MXU matmul
+centroids[j] (K, S) @ e_j (S, Bblk) -> (K, Bblk), masked, and reduced
+by an argmin over the sublane (K) axis straight into row j of the
+(D, Bblk) int32 codes block.  Every block is lane-dense and obeys the
+TPU's (8, 128) tiling, which a (Bblk, 1, S) row-major block breaks.
+The distances never leave VMEM — the fusion win over XLA's unfused path,
+which round-trips the (B, D, K) distance tensor through HBM.
+
+The dot runs at ``Precision.HIGHEST``: at the default precision the
+MXU rounds f32 operands to bf16, which moves near-tied argmins.
 """
 from __future__ import annotations
 
@@ -27,17 +34,19 @@ from jax.experimental import pallas as pl
 
 
 def _assign_kernel(e_ref, cent_ref, klim_ref, codes_ref):
-    e = e_ref[...][:, 0, :]                            # (Bblk, S)
-    cent = cent_ref[...][0]                            # (K, S)
-    k = cent.shape[0]
-    dots = jnp.dot(e, cent.T, preferred_element_type=jnp.float32)
-    c_sq = jnp.sum(jnp.square(cent.astype(jnp.float32)), axis=-1)
-    dist = c_sq[None, :] - 2.0 * dots                  # (Bblk, K)
-    klim = klim_ref[...]                               # (Bblk,)
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-    dist = jnp.where(slot >= klim[:, None], jnp.inf, dist)
-    codes_ref[...] = jnp.argmin(dist, axis=-1
-                                ).astype(jnp.int32)[:, None]
+    n_sub, k, s = cent_ref.shape
+    klim = klim_ref[...]                               # (1, Bblk)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
+    for j in range(n_sub):
+        cent = cent_ref[j].astype(jnp.float32)         # (K, S)
+        e = e_ref[j * s:(j + 1) * s, :].astype(jnp.float32)  # (S, Bblk)
+        dots = jnp.dot(cent, e, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+        c_sq = jnp.sum(jnp.square(cent), axis=1, keepdims=True)
+        dist = c_sq - 2.0 * dots                       # (K, Bblk)
+        dist = jnp.where(slot >= klim, jnp.inf, dist)
+        codes_ref[j:j + 1, :] = jnp.argmin(dist, axis=0
+                                           ).astype(jnp.int32)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -45,7 +54,8 @@ def dpq_assign(e_sub: jax.Array, centroids: jax.Array,
                k_limit: Optional[jax.Array] = None,
                block_b: int = 512, interpret: bool = False) -> jax.Array:
     """e_sub (B, D, S); centroids (D, K, S); k_limit (B,) or None
-    -> codes (B, D) int32."""
+    -> codes (B, D) int32.  On TPU ``block_b`` must be a multiple of
+    128 (rows sit on lanes)."""
     b, d, s = e_sub.shape
     n_sub, k, s2 = centroids.shape
     if (d, s) != (n_sub, s2):
@@ -58,16 +68,17 @@ def dpq_assign(e_sub: jax.Array, centroids: jax.Array,
     if pad:
         e_sub = jnp.pad(e_sub, ((0, pad), (0, 0), (0, 0)))
         k_limit = jnp.pad(k_limit, (0, pad), constant_values=k)
+    e_t = e_sub.reshape(b + pad, d * s).T              # (D*S, B)
     codes = pl.pallas_call(
         _assign_kernel,
-        grid=((b + pad) // block_b, d),
+        grid=((b + pad) // block_b,),
         in_specs=[
-            pl.BlockSpec((block_b, 1, s), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, k, s), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((block_b,), lambda i, j: (i,)),
+            pl.BlockSpec((d * s, block_b), lambda i: (0, i)),
+            pl.BlockSpec((n_sub, k, s), lambda i: (0, 0, 0)),
+            pl.BlockSpec((1, block_b), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((block_b, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b + pad, d), jnp.int32),
+        out_specs=pl.BlockSpec((d, block_b), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((d, b + pad), jnp.int32),
         interpret=interpret,
-    )(e_sub, centroids, k_limit)
-    return codes[:b]
+    )(e_t, centroids, k_limit.reshape(1, b + pad))
+    return codes.T[:b]

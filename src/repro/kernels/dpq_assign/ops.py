@@ -13,7 +13,8 @@ from repro.kernels.dpq_assign.ref import (dpq_assign_blocked_ref,
 
 # The xla entry honours block_b too (scan-blocked so the per-block
 # distance slab stays cache-resident — see ref.py); 64/128 win on CPU,
-# the larger blocks on the MXU-fed paths.
+# the larger blocks on the MXU-fed paths.  The pallas kernel puts rows
+# on lanes, so on TPU it needs a multiple of 128: a sweep there skips 64.
 dispatch.register_op(
     "dpq_assign",
     pallas=lambda e_sub, cent, k_limit=None, block_b=512: dpq_assign(

@@ -11,15 +11,18 @@ HBM table from inside a kernel body, but Pallas lets the BlockSpec
 ``index_map`` read prefetched scalar arrays.  So:
 
   * grid = (nnz,): one id per step
-  * the INPUT block of the table is row ``ids[i]`` — the gather happens
-    in the automatic block DMA, not in the body
-  * the OUTPUT block is bag row ``segment_ids[i]`` — consecutive steps
-    with the same segment revisit the same VMEM block, so the body can
-    accumulate in place.  Pallas keeps a revisited output block resident
-    (it only flushes when the index changes), which is exactly the CSR
-    contract: segment_ids sorted ascending.
-  * at each segment boundary (segment_ids[i] != segment_ids[i-1]) the
-    body resets the accumulator with @pl.when.
+  * blocks are 8 rows tall — the smallest row block the TPU's (8, 128)
+    tiling admits (a (1, d) block is refused)
+  * the INPUT block of the table is the 8-row group holding row
+    ``ids[i]`` — the gather happens in the automatic block DMA, and the
+    body picks the row out with a dynamic sublane slice
+  * the OUTPUT block is the 8-row group holding bag ``segment_ids[i]``
+    — consecutive steps in the same group revisit the same VMEM block,
+    so the body accumulates in place.  Pallas keeps a revisited output
+    block resident (it only flushes when the index changes), which is
+    exactly the CSR contract: segment_ids sorted ascending.
+  * when the step enters a new group (its group differs from the
+    previous step's) the body zeroes the block with @pl.when.
 
 Empty bags are zero-filled by a pre-pass (out init to zeros via
 first-visit reset + a final jnp scatter for untouched bags is avoided
@@ -36,17 +39,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _bag_kernel(ids_ref, seg_ref, w_ref, row_ref, out_ref):
+_ROWS = 8    # sublane tile height: the smallest legal row block
+
+
+def _bag_kernel(ids_ref, seg_ref, w_ref, rows_ref, out_ref):
     i = pl.program_id(0)
     seg = seg_ref[i]
     prev_seg = seg_ref[jnp.maximum(i, 1) - 1]
-    is_first = (i == 0) | (seg != prev_seg)
+    is_first = (i == 0) | (seg // _ROWS != prev_seg // _ROWS)
 
     @pl.when(is_first)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += row_ref[...] * w_ref[i].astype(out_ref.dtype)
+    row = rows_ref[pl.ds(ids_ref[i] % _ROWS, 1), :]           # (1, d)
+    dst = pl.ds(seg % _ROWS, 1)
+    out_ref[dst, :] += row * w_ref[i].astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("num_bags", "interpret"))
@@ -65,6 +73,7 @@ def embedding_bag(table: jax.Array, ids: jax.Array, segment_ids: jax.Array,
         weights = jnp.ones((nnz,), table.dtype)
     ids = ids.astype(jnp.int32)
     segment_ids = segment_ids.astype(jnp.int32)
+    bags_padded = num_bags + (-num_bags) % _ROWS
 
     out = pl.pallas_call(
         _bag_kernel,
@@ -72,15 +81,17 @@ def embedding_bag(table: jax.Array, ids: jax.Array, segment_ids: jax.Array,
             num_scalar_prefetch=3,          # ids, segment_ids, weights
             grid=(nnz,),
             in_specs=[
-                # gather: table row ids[i] is THE block for step i
-                pl.BlockSpec((1, d), lambda i, ids, seg, w: (ids[i], 0)),
+                # gather: the row group of ids[i] is THE block of step i
+                pl.BlockSpec((_ROWS, d),
+                             lambda i, ids, seg, w: (ids[i] // _ROWS, 0)),
             ],
-            out_specs=pl.BlockSpec((1, d), lambda i, ids, seg, w: (seg[i], 0)),
+            out_specs=pl.BlockSpec(
+                (_ROWS, d), lambda i, ids, seg, w: (seg[i] // _ROWS, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((num_bags, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((bags_padded, d), table.dtype),
         interpret=interpret,
     )(ids, segment_ids, weights, table)
 
     # zero-fill bags that never appear (kernel leaves them undefined)
     present = jnp.zeros((num_bags,), jnp.bool_).at[segment_ids].set(True)
-    return jnp.where(present[:, None], out, 0)
+    return jnp.where(present[:, None], out[:num_bags], 0)

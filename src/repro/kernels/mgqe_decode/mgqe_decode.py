@@ -8,11 +8,24 @@ doing it as take_along_axis in HBM would read the centroids once per
 row and defeat the point.
 
 TPU adaptation: per-row dynamic gathers vectorize poorly on the VPU,
-so the gather is re-expressed as a **one-hot matmul** — the MXU eats
-``onehot(codes) @ centroids`` at full throughput:
+so the gather is re-expressed as a **one-hot matmul** — one 2-D MXU
+matmul per subspace, written straight into that subspace's lanes of
+the output block:
 
-    onehot:  (Bblk, D, K)  built from a broadcasted iota compare
-    decode:  einsum('bdk,dks->bds') -> (Bblk, D, S) -> reshape (Bblk, d)
+    onehot_j:  (Bblk, K)  from a broadcasted iota compare on codes[:, j]
+    out[:, j*S:(j+1)*S] = onehot_j @ centroids[j]         (K, S)
+
+A single batched ``einsum('bdk,dks->bds')`` is the textbook form, but
+Mosaic lays the (Bblk, D, K) one-hot out tile-padded and transposed per
+subspace: at Bblk=256 its scoped VMEM reached 33 MB against v5e's
+16 MB limit.  The per-subspace form keeps one (Bblk, K) one-hot live.
+
+Exactness: each output element is ONE centroid value times 1 plus
+zeros, so the matmul is exact provided the MXU does not round its
+operands — f32 runs at ``Precision.HIGHEST`` (the default rounds to
+bf16), and narrower tables are widened to f32 first (XLA:CPU, which
+runs interpret mode, has no bf16 x bf16 -> f32 dot).  Every backend
+therefore returns the centroid values bit-for-bit.
 
 Block layout: grid over B; codes block (Bblk, D) and output block
 (Bblk, d) stream through VMEM; the centroid table is mapped whole into
@@ -20,13 +33,7 @@ VMEM every step (index_map returns the same block).
 
 ``rq_decode_stages`` is the residual-quantization variant (DESIGN.md
 §11): codes (B, M) against M stacked full-width codebooks (M, K, d),
-where the output is the SUM over stages rather than a concatenation
-over subspaces.  Running it as M ``mgqe_decode`` launches (one per
-stage, summed outside) costs M kernel dispatches plus an HBM
-round-trip of the (B, M·d) stage outputs; here the stage sum happens
-in one pass — the grid's innermost dimension iterates stages and the
-revisited (Bblk, dblk) output block accumulates in VMEM, so only the
-final (B, d) sum ever reaches HBM.
+summed over stages in one pass (see its docstring).
 """
 from __future__ import annotations
 
@@ -38,16 +45,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def onehot_dot(onehot: jax.Array, table: jax.Array) -> jax.Array:
+    """``onehot @ table`` in f32, exact (see the module docstring):
+    the one-hot gather of every kernel in this package family."""
+    return jnp.dot(onehot.astype(jnp.float32), table.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _decode_kernel(codes_ref, cent_ref, out_ref):
     codes = codes_ref[...].astype(jnp.int32)          # (Bblk, D)
-    cent = cent_ref[...]                              # (D, K, S)
-    k = cent.shape[1]
-    onehot = (codes[:, :, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2)
-              ).astype(cent.dtype)                    # (Bblk, D, K)
-    dec = jnp.einsum("bdk,dks->bds", onehot, cent,
-                     preferred_element_type=jnp.float32)
-    out_ref[...] = dec.reshape(dec.shape[0], -1).astype(out_ref.dtype)
+    n_sub, k, s = cent_ref.shape
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    for j in range(n_sub):
+        onehot = codes[:, j:j + 1] == slot            # (Bblk, K)
+        dec = onehot_dot(onehot, cent_ref[j])         # (Bblk, S)
+        out_ref[:, j * s:(j + 1) * s] = dec.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -56,8 +69,8 @@ def mgqe_decode(codes: jax.Array, centroids: jax.Array,
     """codes (B, D) int; centroids (D, K, S) -> (B, D*S) float32.
 
     block_b: rows per grid step.  VMEM working set per step =
-    Bblk*D codes + D*K*S*4 centroids + Bblk*D*K*4 onehot + Bblk*d*4 out;
-    256*8*256*4 = 2 MB onehot dominates — comfortably inside 16 MB VMEM.
+    Bblk*D codes + D*K*S centroids (lane-padded) + Bblk*K*4 one-hot +
+    Bblk*d*4 out; at Bblk=256, K=256 the 256 KB one-hot dominates.
     """
     b, d = codes.shape
     n_sub, k, s = centroids.shape
@@ -83,13 +96,16 @@ def mgqe_decode(codes: jax.Array, centroids: jax.Array,
 
 def _staged_kernel(codes_ref, cb_ref, out_ref):
     stage = pl.program_id(2)                          # innermost grid dim
-    codes = codes_ref[...].astype(jnp.int32)          # (Bblk, 1)
+    codes = codes_ref[...].astype(jnp.int32)          # (Bblk, M)
+    # this stage's column, picked by a lane mask: the codes block spans
+    # all M stages (a (Bblk, 1) block breaks the TPU's (8, 128) tiling)
+    lane = jax.lax.broadcasted_iota(jnp.int32, codes.shape, 1)
+    col = jnp.sum(jnp.where(lane == stage, codes, 0), axis=1,
+                  keepdims=True)                      # (Bblk, 1)
     cb = cb_ref[0]                                    # (K, dblk)
     k = cb.shape[0]
-    onehot = (codes
-              == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-              ).astype(cb.dtype)                      # (Bblk, K)
-    dec = jnp.dot(onehot, cb, preferred_element_type=jnp.float32)
+    onehot = col == jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    dec = onehot_dot(onehot, cb)                      # (Bblk, dblk)
 
     @pl.when(stage == 0)
     def _():
@@ -131,7 +147,7 @@ def rq_decode_stages(codes: jax.Array, codebooks: jax.Array,
         _staged_kernel,
         grid=((b + pad) // block_b, d // block_d, m),
         in_specs=[
-            pl.BlockSpec((block_b, 1), lambda i, j, s: (i, s)),
+            pl.BlockSpec((block_b, m), lambda i, j, s: (i, 0)),
             pl.BlockSpec((1, k, block_d), lambda i, j, s: (s, 0, j)),
         ],
         out_specs=pl.BlockSpec((block_b, block_d), lambda i, j, s: (i, j)),

@@ -8,19 +8,22 @@ uint8 layout.  Keeping that byte win requires the unpack to happen
 would read and write the unpacked table and forfeit the reduction.
 
 Per grid step the kernel streams a (Bblk, Wblk) packed block into
-VMEM, widens to int32, shifts/masks the byte lanes apart
-(little-endian within the byte, matching ``pack.pack_codes``), and
-feeds the recovered (Bblk, dblk) codes straight into the same one-hot
+VMEM, widens to int32, and per subspace shifts/masks its code out of
+its byte lane (little-endian within the byte, matching
+``pack.pack_codes``), feeding it straight into the same exact one-hot
 MXU matmul as ``mgqe_decode`` — shift/mask are VPU-friendly lane-wise
-int ops, so the unpack rides along at register bandwidth.
+int ops, so the unpack rides along at register bandwidth.  The unpack
+is per subspace with static shifts: a (Bblk, W, 8 // bits) ->
+(Bblk, D) reshape is a shape cast Mosaic refuses.
 
 Block layout: grid (B/block_b, D/block_d) with ``block_d`` in SUBSPACE
 units.  A subspace tile maps to a byte tile only when it covers whole
 bytes, so ``block_d`` must be a multiple of ``8 // bits`` and divide
 D; anything else falls back to full width (mirrors ``rq_decode_stages``'s
-block_d fallback).  At full width the packed block may carry up to
-``8 // bits - 1`` pad codes in its last byte — the in-kernel slice to
-the centroid count drops them.
+block_d fallback).  On TPU a partial-width block must also span a
+multiple of 128 bytes, so at realistic D only full width compiles.  At
+full width the packed block may carry up to ``8 // bits - 1`` pad codes
+in its last byte — they are never decoded.
 """
 from __future__ import annotations
 
@@ -31,24 +34,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mgqe_decode.mgqe_decode import onehot_dot
 from repro.kernels.packed_decode.pack import PACK_BITS, packed_width
 
 
 def _packed_decode_kernel(packed_ref, cent_ref, out_ref, *, bits):
     packed = packed_ref[...].astype(jnp.int32)        # (Bblk, Wblk)
-    cent = cent_ref[...]                              # (dblk, K, S)
-    dblk, k, _ = cent.shape
+    dblk, k, s = cent_ref.shape
     per_byte = 8 // bits
-    shifts = jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, per_byte), 2) * bits
-    codes = (packed[:, :, None] >> shifts) & (2 ** bits - 1)
-    codes = codes.reshape(codes.shape[0], -1)[:, :dblk]  # (Bblk, dblk)
-    onehot = (codes[:, :, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2)
-              ).astype(cent.dtype)                    # (Bblk, dblk, K)
-    dec = jnp.einsum("bdk,dks->bds", onehot, cent,
-                     preferred_element_type=jnp.float32)
-    out_ref[...] = dec.reshape(dec.shape[0], -1).astype(out_ref.dtype)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    for j in range(dblk):
+        byte = packed[:, j // per_byte:j // per_byte + 1]
+        code = (byte >> ((j % per_byte) * bits)) & (2 ** bits - 1)
+        dec = onehot_dot(code == slot, cent_ref[j])   # (Bblk, S)
+        out_ref[:, j * s:(j + 1) * s] = dec.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -65,7 +64,7 @@ def packed_decode(packed: jax.Array, centroids: jax.Array, bits: int,
     per grid step — must divide D and be a multiple of ``8 // bits``
     (byte-aligned tiles), else full width.  VMEM working set per step is
     the ``mgqe_decode`` one with codes at 1/(8//bits) the bytes:
-    Bblk*Wblk packed + dblk*K*S*4 centroids + Bblk*dblk*K*4 onehot.
+    Bblk*Wblk packed + dblk*K*S*4 centroids + Bblk*K*4 one-hot.
     """
     if bits not in PACK_BITS:
         raise ValueError(f"bits must be one of {PACK_BITS}, got {bits}")

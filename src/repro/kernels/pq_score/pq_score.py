@@ -23,8 +23,11 @@ block (Nblk, D) in VMEM, LUTs pinned whole):
                         is sequential).
 
 The gather ``lut[d, codes[n, d]]`` is one-hot matmul form in all
-three: contraction of ``onehot(codes)`` (Nblk, D, K) with the LUT(s)
-over (D, K) — a single MXU pass per block.
+three: per subspace j, the LUT slice (B, K) contracts with
+``onehot(codes[:, j])`` (Nblk, K) over K — D plain 2-D MXU matmuls
+per block, accumulated in f32.  (A single contraction over both D and
+K is refused by Mosaic: its matmul takes one contracting dim.)  The
+LUTs enter flattened to (B, D*K) so each subspace is a lane slice.
 
 Tie-breaking contract (shared with ``repro.retrieval.topk``): top-k
 entries are ordered by (score desc, id asc); masked/padded slots carry
@@ -43,19 +46,26 @@ INVALID_ID = jnp.iinfo(jnp.int32).max
 
 
 def _onehot_scores(codes, luts):
-    """codes (Nblk, D) int; luts (B, D, K) -> scores (B, Nblk) f32."""
-    k = luts.shape[-1]
-    onehot = (codes[:, :, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2)
-              ).astype(luts.dtype)                     # (Nblk, D, K)
-    return jnp.einsum("ndk,bdk->bn", onehot, luts,
-                      preferred_element_type=jnp.float32)
+    """codes (Nblk, D) int; luts (B, D*K) -> scores (B, Nblk) f32."""
+    d = codes.shape[1]
+    k = luts.shape[1] // d
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    scores = None
+    for j in range(d):
+        onehot = (codes[:, j:j + 1] == slot).astype(jnp.float32)
+        part = jax.lax.dot_general(
+            luts[:, j * k:(j + 1) * k].astype(jnp.float32), onehot,
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)       # (B, Nblk)
+        scores = part if scores is None else scores + part
+    return scores
 
 
 def _score_kernel(codes_ref, lut_ref, out_ref):
     codes = codes_ref[...].astype(jnp.int32)          # (Nblk, D)
-    scores = _onehot_scores(codes, lut_ref[...][None])  # (1, Nblk)
-    out_ref[...] = scores[0].astype(out_ref.dtype)
+    scores = _onehot_scores(codes, lut_ref[...])      # (1, Nblk)
+    out_ref[...] = scores.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -74,13 +84,13 @@ def pq_score(lut: jax.Array, codes: jax.Array, block_n: int = 1024,
         grid=((n + pad) // block_n,),
         in_specs=[
             pl.BlockSpec((block_n, n_sub), lambda i: (i, 0)),
-            pl.BlockSpec((n_sub, k), lambda i: (0, 0)),
+            pl.BlockSpec((1, n_sub * k), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n + pad,), jnp.float32),
+        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n + pad), jnp.float32),
         interpret=interpret,
-    )(codes, lut)
-    return out[:n]
+    )(codes, lut.reshape(1, n_sub * k))
+    return out[0, :n]
 
 
 def _score_batched_kernel(codes_ref, luts_ref, out_ref):
@@ -106,12 +116,12 @@ def pq_score_batched(luts: jax.Array, codes: jax.Array,
         grid=((n + pad) // block_n,),
         in_specs=[
             pl.BlockSpec((block_n, n_sub), lambda i: (i, 0)),
-            pl.BlockSpec((b, n_sub, k), lambda i: (0, 0, 0)),
+            pl.BlockSpec((b, n_sub * k), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((b, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b, n + pad), jnp.float32),
         interpret=interpret,
-    )(codes, luts)
+    )(codes, luts.reshape(b, n_sub * k))
     return out[:, :n]
 
 
@@ -163,12 +173,12 @@ def pq_topk(luts: jax.Array, codes: jax.Array, k: int,
         grid=((n + pad) // block_n,),
         in_specs=[
             pl.BlockSpec((block_n, n_sub), lambda i: (i, 0)),
-            pl.BlockSpec((b, n_sub, kk), lambda i: (0, 0, 0)),
+            pl.BlockSpec((b, n_sub * kk), lambda i: (0, 0)),
         ],
         out_specs=(pl.BlockSpec((b, k), lambda i: (0, 0)),
                    pl.BlockSpec((b, k), lambda i: (0, 0))),
         out_shape=(jax.ShapeDtypeStruct((b, k), jnp.float32),
                    jax.ShapeDtypeStruct((b, k), jnp.int32)),
         interpret=interpret,
-    )(codes, luts)
+    )(codes, luts.reshape(b, n_sub * kk))
     return scores, ids

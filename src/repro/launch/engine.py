@@ -208,14 +208,21 @@ class _MicroBatchEngine:
         self.stats_.flushes += 1
         return out
 
-    def serve_stream(self, requests: Sequence[np.ndarray]) -> EngineStats:
+    def serve_stream(self, requests: Sequence[np.ndarray],
+                     outputs: Optional[list] = None) -> EngineStats:
         """Drive a request stream through the micro-batcher; flush
-        whenever the queue reaches max_queue, once more at the end."""
+        whenever the queue reaches max_queue, once more at the end.
+        ``outputs``, when given, receives each request's result in
+        stream order."""
         for r in requests:
             self.submit(r)
             if self.should_flush():
-                self.flush()
-        self.flush()
+                res = self.flush()
+                if outputs is not None:
+                    outputs.extend(res)
+        res = self.flush()
+        if outputs is not None:
+            outputs.extend(res)
         return self.stats_
 
     def stats(self) -> EngineStats:
@@ -642,7 +649,8 @@ def drive_random_stream(engine: ServingEngine, vocab_size: int,
 
 def drive_zipf_stream(engine: ServingEngine, vocab_size: int,
                       n_requests: int, req_batch: int,
-                      zipf_a: float = 1.2, seed: int = 0) -> EngineStats:
+                      zipf_a: float = 1.2, seed: int = 0,
+                      outputs: Optional[list] = None) -> EngineStats:
     """Power-law twin of :func:`drive_random_stream`: Zipf(``zipf_a``)
     ids over the frequency-sorted vocabulary — the head-heavy traffic
     the hot-row cache exists for (DESIGN.md §9).
@@ -653,7 +661,8 @@ def drive_zipf_stream(engine: ServingEngine, vocab_size: int,
     hits — zero XLA compile time in the returned stats.  (Auto-refresh
     between passes can shift the cached set and re-trace a handful of
     shapes; the EMA counters and stats are reset so the measured pass
-    starts clean either way.)"""
+    starts clean either way.)  ``outputs`` receives the measured
+    pass's per-request results."""
     from repro.data.synthetic import zipf_request_stream
     reqs = zipf_request_stream(vocab_size, n_requests, req_batch,
                                zipf_a=zipf_a, seed=seed)
@@ -661,7 +670,7 @@ def drive_zipf_stream(engine: ServingEngine, vocab_size: int,
     engine.stats_ = EngineStats()
     if engine._freq is not None:
         engine._freq[:] = 0.0
-    return engine.serve_stream(reqs)
+    return engine.serve_stream(reqs, outputs)
 
 
 def drive_random_query_stream(engine: RetrievalEngine, dim: int,

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,7 @@ import numpy as np
 
 from repro.configs.registry import get_arch
 from repro.core.types import KERNEL_BACKENDS
+from repro.launch.compile_cache import setup_compile_cache
 
 
 def parse_mesh(spec: str):
@@ -166,30 +168,53 @@ def serve_retrieval(cfg, n_candidates: int, index_kind: str = "flat_pq",
     print(f"recall@{topk} vs exact dense scan: {rec:.3f}")
 
 
-def serve_ctr(cfg, batch: int):
+class CtrServed(NamedTuple):
+    """What :func:`serve_ctr` built and served, for callers that check
+    it (``chip_smoke.py``): ``step`` is the jitted serve function,
+    called as ``step(params, artifacts, request)``."""
+    model: Any
+    params: Dict
+    artifacts: Dict
+    request: Dict
+    step: Callable
+    scores: jax.Array
+
+
+def serve_ctr(cfg, batch: int, params=None, artifacts=None) -> CtrServed:
+    """Serve one ``batch``-example CTR request through the jitted
+    ``model.serve``.  ``params`` defaults to a fresh init and
+    ``artifacts`` to its export (codes through the dispatched
+    ``dpq_assign``); passing both serves an existing artifact, e.g.
+    under another kernel backend."""
     from repro.launch.cells import _recsys_model
     model = _recsys_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    if cfg.model == "bst":
-        artifacts = model.item_emb.export(params["item_emb"])
-        rng = np.random.default_rng(0)
-        b = {"hist_ids": jnp.asarray(
-                 rng.integers(0, cfg.n_items, (batch, cfg.seq_len)),
-                 jnp.int32),
-             "target_id": jnp.asarray(
-                 rng.integers(0, cfg.n_items, batch), jnp.int32)}
+    if params is None:
+        params = model.init(jax.random.PRNGKey(0))
+    bst = cfg.model == "bst"
+    if artifacts is None:
+        t0 = time.time()
+        artifacts = (model.item_emb.export(params["item_emb"]) if bst
+                     else model.fields.export(params["fields"]))
+        jax.block_until_ready(artifacts)
+        print(f"exported serving artifacts in {time.time()-t0:.2f}s")
+    rng = np.random.default_rng(0)
+    if bst:
+        request = {"hist_ids": jnp.asarray(
+                       rng.integers(0, cfg.n_items, (batch, cfg.seq_len)),
+                       jnp.int32),
+                   "target_id": jnp.asarray(
+                       rng.integers(0, cfg.n_items, batch), jnp.int32)}
     else:
-        artifacts = model.fields.export(params["fields"])
-        rng = np.random.default_rng(0)
         ids = np.stack([rng.integers(0, v, batch)
                         for v in cfg.field_vocab_sizes], 1)
-        b = {"sparse_ids": jnp.asarray(ids, jnp.int32)}
+        request = {"sparse_ids": jnp.asarray(ids, jnp.int32)}
+    step = jax.jit(lambda p, a, bb: model.serve(p, a, bb))
     t0 = time.time()
-    scores = jax.jit(lambda p, a, bb: model.serve(p, a, bb))(
-        params, artifacts, b)
+    scores = step(params, artifacts, request)
     jax.block_until_ready(scores)
-    print(f"served B={batch} in {time.time()-t0:.2f}s; "
-          f"scores mean {float(jnp.mean(scores)):.4f}")
+    print(f"served B={batch} in {time.time()-t0:.2f}s (compile "
+          f"included); scores mean {float(jnp.mean(scores)):.4f}")
+    return CtrServed(model, params, artifacts, request, step, scores)
 
 
 def serve_async_engine(engine, vocab_size: int, req_batch: int,
@@ -266,7 +291,8 @@ def serve_engine(family, cfg, n_requests: int, req_batch: int,
                 f"{jax.device_count()} (XLA_FLAGS was set too late? "
                 f"export XLA_FLAGS=--xla_force_host_platform_device_"
                 f"count={need})")
-        mesh = jax.make_mesh(shape, axes)
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(shape, axes)
         model_n = dict(mesh.shape).get("model", 1)
         # size report only for quantized artifacts; other kinds fall
         # through so ServingEngine raises its designed ValueError.
@@ -379,6 +405,7 @@ def main():
                     help="serve the engine's artifact sharded over this "
                          "mesh (codes over 'model', batch over the rest)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.mesh and not args.engine:
         ap.error("--mesh requires --engine")

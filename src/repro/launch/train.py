@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import setup_compile_cache
 from repro.train import optimizer as opt_lib
 from repro.train.loop import LoopConfig, fit
 from repro.train.optimizer import TrainState
@@ -44,7 +45,7 @@ def _lm_setup(cfg, batch: int, seq: int):
     return state, step, data()
 
 
-def _recsys_setup(cfg, batch: int):
+def recsys_setup(cfg, batch: int):
     from repro.data.synthetic import CTRStream
     from repro.launch.cells import _recsys_model
     model = _recsys_model(cfg)
@@ -131,12 +132,13 @@ def main():
                     help="inject a crash at this step (tests restart)")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    setup_compile_cache()
 
     family, cfg = get_arch(args.arch, smoke=args.smoke)
     if family == "lm":
         state, step, data = _lm_setup(cfg, args.batch, args.seq)
     elif family == "recsys":
-        state, step, data = _recsys_setup(cfg, args.batch)
+        state, step, data = recsys_setup(cfg, args.batch)
     else:
         state, step, data = _gnn_setup(cfg, args.batch)
 

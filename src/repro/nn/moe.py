@@ -32,16 +32,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map
 
 from repro.nn import initializers as init
 
 
 def ambient_mesh():
     """The physical mesh installed by ``with mesh:`` (trace-time)."""
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty:
+    from repro.sharding.gather import ambient_mesh as _ambient
+    mesh = _ambient()
+    if mesh is None:
         raise RuntimeError("moe_ffn_sharded needs an ambient mesh "
                            "(wrap the jit call in `with mesh:`)")
     return mesh
@@ -200,10 +199,10 @@ def moe_ffn_sharded(params: dict, x: jax.Array, *, top_k: int,
         # expert GEMMs split over d_ff
         x_spec = P(data_axes, None, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), w_spec, w_spec, wd_spec, x_spec),
         out_specs=(x_spec, P()),
-        check=False)
+        check_vma=False)
     return fn(params["router"], params["w_gate"], params["w_up"],
               params["w_down"], x)

@@ -36,8 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.retrieval.base import Index
 from repro.retrieval.topk import merge_topk
-from repro.sharding.compat import shard_map
-from repro.sharding.gather import _ambient_mesh, data_shard_index
+from repro.sharding.gather import ambient_mesh, data_shard_index
 
 
 def sharded_topk(index: Index, artifact: Dict, queries: jax.Array,
@@ -50,7 +49,7 @@ def sharded_topk(index: Index, artifact: Dict, queries: jax.Array,
     Falls back to the single-device search when no usable mesh is
     ambient or the row counts don't divide — call sites never branch.
     """
-    mesh = mesh or _ambient_mesh()
+    mesh = mesh or ambient_mesh()
     if mesh is None or mesh.size == 1 or model_axis not in mesh.axis_names:
         return index.search(artifact, queries, k)
     if not index.supports_sharded:
@@ -96,11 +95,11 @@ def sharded_topk(index: Index, artifact: Dict, queries: jax.Array,
         return ms, mi
 
     art_specs = index.artifact_shard_specs(artifact, model_axis=model_axis)
-    topk_sm = shard_map(
+    topk_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(art_specs, P(data_axes or None, None)),
         out_specs=(P(data_axes or None, None), P(data_axes or None, None)),
-        check=False)
+        check_vma=False)
     scores, ids = topk_sm(artifact, queries)
     return scores[:b], ids[:b]
 

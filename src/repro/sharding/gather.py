@@ -23,10 +23,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map
 
 
-def _ambient_mesh():
+def ambient_mesh():
+    """The mesh installed by ``with mesh:`` (read at trace time), or
+    None outside one."""
     from jax.interpreters import pxla
     mesh = pxla.thread_resources.env.physical_mesh
     return None if mesh.empty else mesh
@@ -53,7 +54,7 @@ def row_gather(table: jax.Array, ids: jax.Array,
     """
     if not sharded:
         return jnp.take(table, ids, axis=0)
-    mesh = _ambient_mesh()
+    mesh = ambient_mesh()
     if mesh is None or mesh.size == 1 or model_axis not in mesh.axis_names:
         return jnp.take(table, ids, axis=0)
 
@@ -103,16 +104,16 @@ def row_gather(table: jax.Array, ids: jax.Array,
         dt = dt.at[local].add(dout * hit[:, None].astype(dout.dtype))
         return dt
 
-    gather_sm = shard_map(
+    gather_sm = jax.shard_map(
         fwd_body, mesh=mesh,
         in_specs=(P(model_axis, None), P(data_axes or None)),
         out_specs=P(data_axes or None, None),
-        check=False)
-    scatter_sm = shard_map(
+        check_vma=False)
+    scatter_sm = jax.shard_map(
         bwd_body, mesh=mesh,
         in_specs=(P(data_axes or None), P(data_axes or None, None)),
         out_specs=P(model_axis, None),      # identical across data: no psum
-        check=False)
+        check_vma=False)
 
     @jax.custom_vjp
     def _gather(table, ids_flat):

@@ -45,8 +45,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.schemes import get_scheme, registered_kinds, scheme_class
 from repro.core.types import EmbeddingConfig
-from repro.sharding.compat import shard_map
-from repro.sharding.gather import _ambient_mesh, data_shard_index
+from repro.sharding.gather import ambient_mesh, data_shard_index
 
 
 def supports_sharding(kind: str, variant: str = "-") -> bool:
@@ -103,7 +102,7 @@ def quantized_gather(artifact: dict, ids: jax.Array, cfg: EmbeddingConfig,
     scheme = get_scheme(cfg)
     if not scheme.supports_sharded_codes:
         raise ValueError(f"cannot shard codes of kind={cfg.kind!r}")
-    mesh = mesh or _ambient_mesh()
+    mesh = mesh or ambient_mesh()
     if mesh is None or mesh.size == 1 or model_axis not in mesh.axis_names:
         return scheme.decode(artifact, ids)
 
@@ -152,11 +151,11 @@ def quantized_gather(artifact: dict, ids: jax.Array, cfg: EmbeddingConfig,
         return full
 
     art_specs = scheme.artifact_shard_specs(model_axis=model_axis)
-    gather_sm = shard_map(
+    gather_sm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(art_specs, P(data_axes or None)),
         out_specs=P(data_axes or None, None),
-        check=False)
+        check_vma=False)
     out = gather_sm(artifact, flat_ids)[:flat]
     return out.reshape(lead + (d_out,))
 

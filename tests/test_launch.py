@@ -63,3 +63,31 @@ def test_grad_accumulation_matches_full_batch():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=2e-3, atol=2e-5)
+
+
+def test_compile_cache_leaves_env_dir_in_charge(monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR set: the helper reports it and sets
+    no directory of its own."""
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/cache")
+    assert compile_cache.setup_compile_cache() == "/somewhere/cache"
+    assert calls == []
+
+
+def test_compile_cache_fixed_repo_path(monkeypatch):
+    """Unset: the cache goes to the fixed <repo>/.jax_cache — the same
+    path in every process, so entries are found again."""
+    import os
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.setup_compile_cache() == want
+    assert compile_cache.setup_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)] * 2

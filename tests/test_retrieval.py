@@ -308,7 +308,8 @@ def test_retrieval_engine_rejects_bad_mesh_configs():
     index = get_index(IndexConfig(kind="flat_pq", num_subspaces=4,
                                   num_centroids=16, iters=3))
     art = index.build(jax.random.PRNGKey(0), vecs)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="model"):
         RetrievalEngine(index, art, k=5, mesh=mesh)
 

@@ -261,7 +261,8 @@ def test_host_staged_engine_rejects_flat_and_mesh():
         RetrievalEngine(fidx, fart, k=10, host_staged=True)
     iidx = get_index(_cfg("ivf_pq", host_staged=True))
     iart = iidx.build(jax.random.PRNGKey(0), vecs)
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="single-device"):
         RetrievalEngine(iidx, iart, k=10, mesh=mesh)
 

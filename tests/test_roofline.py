@@ -81,7 +81,6 @@ ENTRY %main (p0: f32[128,4]) -> f32[128,4] {
 def test_collectives_inside_while_weighted():
     """A psum inside a scanned body must count once per iteration."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.sharding.compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
@@ -90,10 +89,10 @@ def test_collectives_inside_while_weighted():
             return c + jax.lax.psum(x, "x"), None
         return jax.lax.scan(body, jnp.zeros((64,)), xs)[0]
 
-    # check=False: the scan carry's replication type flips under psum,
-    # which strict replication checking rejects on a 1-device mesh
-    f = shard_map(inner, mesh=mesh, in_specs=P(None, None), out_specs=P(),
-                  check=False)
+    # check_vma=False: the scan carry's replication type flips under
+    # psum, which strict replication checking rejects on a 1-device mesh
+    f = jax.shard_map(inner, mesh=mesh, in_specs=P(None, None),
+                      out_specs=P(), check_vma=False)
     txt = jax.jit(f).lower(jnp.ones((5, 64))).compile().as_text()
     c = analyze(txt)
     # 5 iterations x 64 f32 = 1280 bytes (if XLA keeps the psum; on a

@@ -153,7 +153,8 @@ def test_sharded_quantized_gather_matches_serve_all_variants():
             dict(kind="mpe", num_subspaces=8, tier_boundaries=(16, 48),
                  tier_bits=(8, 4, 2)),
         ]
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         assert dict(mesh.shape) == {"data": 2, "model": 2}
         for kw in variants:
             cfg = EmbeddingConfig(vocab_size=128, dim=16, **kw)
@@ -190,7 +191,8 @@ def test_sharded_rq_single_pass_decode_bit_identical():
         from repro.core import Embedding, EmbeddingConfig
         from repro.sharding.rules import shard_quantized_artifact
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         for backend in ("xla", "interpret"):
             cfg = EmbeddingConfig(vocab_size=128, dim=16, kind="rq",
                                   num_levels=3, num_centroids=8,
@@ -229,7 +231,8 @@ def test_sharded_mpe_packed_decode_bit_identical():
         from repro.core import Embedding, EmbeddingConfig
         from repro.sharding.rules import shard_quantized_artifact
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         for backend in ("xla", "interpret"):
             cfg = EmbeddingConfig(vocab_size=128, dim=16, kind="mpe",
                                   num_subspaces=8,
@@ -275,7 +278,8 @@ def test_sharded_engine_matches_single_device():
                               decode_block_b=32)
         emb = Embedding(cfg)
         art = emb.export(emb.init(jax.random.PRNGKey(0)))
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         eng = ServingEngine(emb, art, mesh=mesh)
         ref_eng = ServingEngine(emb, art)
         assert eng.pad_multiple == 32 * 2 and eng.data_shards == 2
@@ -317,7 +321,8 @@ def test_sharded_engine_hot_cache_bit_identical():
             dict(kind="mpe", num_subspaces=8, tier_boundaries=(16, 48),
                  tier_bits=(8, 4, 2)),
         ]
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         rng = np.random.default_rng(0)
         for kw in variants:
             cfg = EmbeddingConfig(vocab_size=128, dim=16,
@@ -367,7 +372,8 @@ def test_sharded_retrieval_topk_bit_identical_all_kinds():
                                      sharded_topk)
         from repro.sharding.rules import shard_retrieval_artifact
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         vecs = jax.random.normal(jax.random.PRNGKey(0), (2048, 16))
         q = jax.random.normal(jax.random.PRNGKey(1), (8, 16))
         for kind in registered_index_kinds():
@@ -459,7 +465,8 @@ def test_sharded_rows_train_lookup_private_variants():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import Embedding, EmbeddingConfig
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for variant, extra in [
                 ("private_k", dict(tier_num_centroids=(8, 4))),
                 ("private_d", dict(tier_num_subspaces=(4, 2)))]:
@@ -503,7 +510,8 @@ def test_moe_sharded_dispatch_matches_reference():
         import warnings; warnings.filterwarnings('ignore')
         import jax, jax.numpy as jnp
         from repro.nn import moe as moe_lib
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ('data', 'model'))
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
         for e in (8, 3):   # 8 -> expert strategy; 3 -> ffn strategy
@@ -523,7 +531,8 @@ def test_sharded_row_gather_matches_take():
         import warnings; warnings.filterwarnings('ignore')
         import jax, jax.numpy as jnp, numpy as np
         from repro.sharding.gather import row_gather
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ('data', 'model'))
         table = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
         ids = jax.random.randint(jax.random.PRNGKey(1), (8,), 0, 64)
         ref = jnp.take(table, ids, axis=0)

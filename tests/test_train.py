@@ -207,7 +207,6 @@ def test_compressed_psum_mean_single_device():
     must equal the (dequantized) local gradient."""
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.compat import shard_map
     from repro.train.compression import compressed_psum_mean
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
@@ -218,9 +217,9 @@ def test_compressed_psum_mean_single_device():
     def f(g, e):
         return compressed_psum_mean(g, e, "dp")
 
-    out, new_err = jax.jit(shard_map(
+    out, new_err = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        check=False))(g, err)
+        check_vma=False))(g, err)
     q, scale = quantize_int8(g["w"])
     np.testing.assert_allclose(np.asarray(out["w"]),
                                np.asarray(dequantize(q, scale)), rtol=1e-6)

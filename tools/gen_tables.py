@@ -102,8 +102,9 @@ def support_matrix():
         emb = Embedding(cfg)
         art = emb.export(emb.init(jax.random.PRNGKey(0)))
         ids = jax.numpy.arange(8)
-        cells = [probe(lambda b=b: dispatch.get_impl("mgqe_decode", b))
-                 for b in backends]
+        # registration, not resolution: pallas only resolves on a TPU
+        cells = [probe(lambda b=b: dispatch.registered_ops()
+                       ["mgqe_decode"][b]) for b in backends]
         cells.append(probe(lambda: emb.serve(art, ids)))
         cells.append("✓" if supports_sharding(kind, var)
                      and probe(lambda: quantized_artifact_specs(cfg)) == "✓"
@@ -130,7 +131,7 @@ def support_matrix():
     for kind in registered_index_kinds():
         index = get_index(idx_class(kind).probe_config())
         art = index.build(jax.random.PRNGKey(1), vecs)
-        cells = [probe(lambda b=b: dispatch.get_impl("pq_topk", b))
+        cells = [probe(lambda b=b: dispatch.registered_ops()["pq_topk"][b])
                  for b in r_backends]
         cells.append(probe(lambda: index.search(art, vecs[:4], 5)))
         cells.append("✓" if index.supports_sharded
