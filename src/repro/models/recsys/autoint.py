@@ -62,6 +62,7 @@ class AutoInt:
                                      dtype=dtype),
         }
 
+    @jax.named_scope("tower")
     def _interact(self, params: Dict, x: jax.Array) -> jax.Array:
         for p in params["layers"]:
             x = _interact_layer(p, x, self.cfg.n_attn_heads, self.cfg.d_attn)
@@ -79,8 +80,9 @@ class AutoInt:
 
     def loss(self, params: Dict, batch: Dict) -> Tuple[jax.Array, Dict]:
         logits, aux = self.apply(params, batch)
-        y = batch["label"].astype(jnp.float32)
-        bce = jnp.mean(jnp.maximum(logits, 0) - logits * y
-                       + jnp.log1p(jnp.exp(-jnp.abs(logits))))
-        loss = bce + aux
+        with jax.named_scope("tower"):
+            y = batch["label"].astype(jnp.float32)
+            bce = jnp.mean(jnp.maximum(logits, 0) - logits * y
+                           + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+            loss = bce + aux
         return loss, {"loss": loss, "bce": bce, "aux": aux}

@@ -47,12 +47,14 @@ class DeepFM:
         sq = jnp.sum(jnp.square(x), axis=1)
         return 0.5 * jnp.sum(jnp.square(s) - sq, axis=-1)
 
+    @jax.named_scope("tower")
     def _logit(self, params: Dict, x: jax.Array, fo: jax.Array) -> jax.Array:
         b = x.shape[0]
         fm = self._fm(x)
         deep = mlp(params["mlp"], x.reshape(b, -1), act="relu")[:, 0]
         return fm + deep + fo + params["bias"]
 
+    @jax.named_scope("first_order")
     def _first_order(self, params: Dict, ids: jax.Array) -> jax.Array:
         total = jnp.zeros((ids.shape[0],), jnp.float32)
         for i, e in enumerate(self.first_order):
@@ -74,8 +76,9 @@ class DeepFM:
 
     def loss(self, params: Dict, batch: Dict) -> Tuple[jax.Array, Dict]:
         logits, aux = self.apply(params, batch)
-        y = batch["label"].astype(jnp.float32)
-        bce = jnp.mean(jnp.maximum(logits, 0) - logits * y
-                       + jnp.log1p(jnp.exp(-jnp.abs(logits))))
-        loss = bce + aux
+        with jax.named_scope("tower"):
+            y = batch["label"].astype(jnp.float32)
+            bce = jnp.mean(jnp.maximum(logits, 0) - logits * y
+                           + jnp.log1p(jnp.exp(-jnp.abs(logits))))
+            loss = bce + aux
         return loss, {"loss": loss, "bce": bce, "aux": aux}

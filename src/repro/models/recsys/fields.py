@@ -79,8 +79,10 @@ class FieldEmbeddings:
         return {f"f{i}": e.init(k, dtype=dtype)
                 for i, (e, k) in enumerate(zip(self.embs, keys))}
 
+    @jax.named_scope("fields")
     def apply(self, params: Dict, ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        """ids (B, F) -> ((B, F, d), aux_loss)."""
+        """ids (B, F) -> ((B, F, d), aux_loss), under the named scope
+        ``fields`` (its gradient: ``transpose(jvp(fields))``)."""
         outs, aux = [], jnp.float32(0.0)
         for i, e in enumerate(self.embs):
             o, a = e.apply(params[f"f{i}"], ids[:, i])
@@ -92,6 +94,7 @@ class FieldEmbeddings:
         return {f"f{i}": e.export(params[f"f{i}"])
                 for i, e in enumerate(self.embs)}
 
+    @jax.named_scope("fields")
     def serve(self, artifacts: Dict, ids: jax.Array) -> jax.Array:
         outs = [e.serve(artifacts[f"f{i}"], ids[:, i])
                 for i, e in enumerate(self.embs)]

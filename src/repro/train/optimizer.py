@@ -180,16 +180,22 @@ def clip_by_global_norm(grads, max_norm: float):
     return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads), norm
 
 
+@jax.named_scope("optimizer")
 def apply_updates(cfg: OptimizerConfig, params, grads,
                   state: Dict) -> Tuple[Any, Dict]:
+    """One update, under the named scope ``optimizer`` (nested: ``clip``
+    and the rule's kind), which its device ops carry in their HLO
+    ``op_name``."""
     rule = _rule(cfg.kind)
     step = state["step"]
     lr = schedule_lr(cfg, step)
     if cfg.grad_clip is not None:
-        grads, _ = clip_by_global_norm(grads, cfg.grad_clip)
+        with jax.named_scope("clip"):
+            grads, _ = clip_by_global_norm(grads, cfg.grad_clip)
     moments = {k: state[k] for k in rule.state_keys}
-    new_params, new_moments = rule.update(cfg, lr, step, params, grads,
-                                          moments)
+    with jax.named_scope(cfg.kind):
+        new_params, new_moments = rule.update(cfg, lr, step, params, grads,
+                                              moments)
     return new_params, {"step": step + 1, **new_moments}
 
 

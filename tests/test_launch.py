@@ -91,3 +91,80 @@ def test_compile_cache_fixed_repo_path(monkeypatch):
     assert compile_cache.setup_compile_cache() == want
     assert compile_cache.setup_compile_cache() == want
     assert calls == [("jax_compilation_cache_dir", want)] * 2
+
+
+def _record(name):
+    from repro.launch import compile_cache
+    return compile_cache.compile_stats().get(name, {})
+
+
+def test_compile_stats_per_function_and_persistent_cache(tmp_path,
+                                                         monkeypatch):
+    """One jit compiled: its record names it; in a fresh persistent
+    cache the first compile misses and, after ``jax.clear_caches()``,
+    the second hits."""
+    from jax._src import compilation_cache as cc
+    from repro.launch import compile_cache
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    compile_cache.setup_compile_cache()
+    compile_cache.setup_compile_cache()     # listeners installed once
+    old = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    cc.reset_cache()
+    try:
+        def compile_stats_probe(x):
+            return jnp.tanh(x) * 3.0 + 1.0
+        f = jax.jit(compile_stats_probe)
+        x = jnp.arange(7.0)
+        f(x).block_until_ready()
+        first = _record("jit(compile_stats_probe)")
+        assert first["compiles"] == 1 and first["compile_s"] > 0
+        assert first["traces"] == 1 and first["trace_s"] > 0
+        assert (first["cache_misses"], first["cache_hits"]) == (1, 0)
+        jax.clear_caches()
+        jax.jit(compile_stats_probe)(x).block_until_ready()
+        second = _record("jit(compile_stats_probe)")
+        assert second["compiles"] == 2
+        assert (second["cache_misses"], second["cache_hits"]) == (1, 1)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          old[1])
+        cc.reset_cache()
+
+
+def test_compile_stats_keep_threads_apart(monkeypatch):
+    """A hit on one thread and a miss on another, interleaved before
+    either compile ends: each goes to its own thread's compile."""
+    import threading
+    from repro.launch import compile_cache as C
+    monkeypatch.setenv(C.ENV_VAR, "/unused")
+    C.setup_compile_cache()
+    hit, miss = list(C.CACHE_EVENTS)
+    a_waits, b_done = threading.Event(), threading.Event()
+
+    def a():
+        jax.monitoring.record_event(hit)
+        a_waits.set()
+        b_done.wait(10)
+        jax.monitoring.record_event_duration_secs(
+            C.BACKEND_COMPILE_EVENT, 0.5, fun_name="jit(thread_a_fn)")
+
+    def b():
+        a_waits.wait(10)
+        jax.monitoring.record_event(miss)
+        jax.monitoring.record_event_duration_secs(
+            C.BACKEND_COMPILE_EVENT, 0.25, fun_name="jit(thread_b_fn)")
+        b_done.set()
+    threads = [threading.Thread(target=t) for t in (a, b)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(10)
+    ra, rb = _record("jit(thread_a_fn)"), _record("jit(thread_b_fn)")
+    assert (ra["compiles"], ra["compile_s"]) == (1, 0.5)
+    assert (ra["cache_hits"], ra["cache_misses"]) == (1, 0)
+    assert (rb["compiles"], rb["compile_s"]) == (1, 0.25)
+    assert (rb["cache_hits"], rb["cache_misses"]) == (0, 1)
