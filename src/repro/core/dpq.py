@@ -85,15 +85,110 @@ def assign_codes(e_sub: jax.Array, centroids: jax.Array,
     return jnp.argmin(dist, axis=-1).astype(jnp.int32)
 
 
+# Lane width of a TPU vector register and MXU tile.
+LANES = 128
+
+
 def decode_codes(codes: jax.Array, centroids: jax.Array) -> jax.Array:
-    """codes (..., D) -> concatenated centroid vectors (..., D, S)."""
-    # take_along_axis over the K axis of (D, K, S)
-    d = centroids.shape[0]
-    gathered = jnp.take_along_axis(
-        centroids[None], codes[..., None, None].reshape((-1, d, 1, 1)),
-        axis=2)                                   # (B*, D, 1, S)
-    out = gathered[:, :, 0, :]
-    return out.reshape(codes.shape + (centroids.shape[-1],))
+    """codes (..., D) -> concatenated centroid vectors (..., D*S).
+
+    Bit-identical to a gather of the selected centroids, but computed
+    as a one-hot product on the MXU (:func:`_onehot_decode`), whose
+    gradient is a second product.  A gather and its scatter-add
+    gradient run serially on a TPU, one row of S floats at a time.
+    """
+    num_sub, _, sub_dim = centroids.shape
+    lead = codes.shape[:-1]
+    with jax.named_scope("codebook"):
+        out = _onehot_decode(codes.reshape(-1, num_sub), centroids)
+    return out.reshape(lead + (num_sub * sub_dim,))
+
+
+def _bf16_pieces(x: jax.Array) -> jax.Array:
+    """f32 (..., n) -> bf16 (..., 3n): pieces hi | mid | lo with
+    hi + mid + lo == x exactly (each takes the next 8 significant bits
+    of a normal f32), side by side on the last axis."""
+    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    rest = x - hi
+    mid = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+    return jnp.concatenate([hi, mid, rest - mid],
+                           axis=-1).astype(jnp.bfloat16)
+
+
+def _sum_pieces(p: jax.Array) -> jax.Array:
+    """(..., 3n) f32 products of :func:`_bf16_pieces` -> (..., n)."""
+    n = p.shape[-1] // 3
+    return (p[..., :n] + p[..., n:2 * n]) + p[..., 2 * n:]
+
+
+def _group(num_sub: int, sub_dim: int) -> int:
+    """Subspaces per block of the block-diagonal codebook: the most
+    that divide D and keep a block's three pieces within one lane tile
+    (all of them for narrow rows; one for wide subspaces, where a
+    block-diagonal would multiply by zeros D times over)."""
+    return max(g for g in range(1, num_sub + 1)
+               if num_sub % g == 0 and (g == 1 or 3 * g * sub_dim <= LANES))
+
+
+def _onehot(codes: jax.Array, k: int) -> jax.Array:
+    """codes (B, G) -> bf16 one-hot (B, G, K), exact in bf16.  Kept 3-D
+    (the products contract G and K together): a reshape to (B, G*K)
+    here made the TPU compiler write the broadcast codes to HBM."""
+    # explicit rank match (sanitizer lane runs rank_promotion='raise')
+    slot = jnp.arange(k, dtype=codes.dtype).reshape(1, 1, k)
+    return (codes[:, :, None] == slot).astype(jnp.bfloat16)
+
+
+@jax.custom_vjp
+def _onehot_decode(codes: jax.Array, centroids: jax.Array) -> jax.Array:
+    """codes (B, D) int, centroids (D, K, S) f32 -> (B, D*S) f32.
+
+    Per block of G subspaces: the (B, G, K) one-hot times the
+    block-diagonal (G, K, G*S) codebook, cut into three bf16 pieces side
+    by side, in one bf16 product with f32 accumulation.  Every output
+    is one piece times 1 plus zeros, and the pieces sum to the centroid
+    exactly: the arithmetic of ``Precision.HIGHEST`` less its passes on
+    the one-hot, which bf16 holds exactly.  Only ``codes`` (and the
+    small codebook) are kept for the gradient, which rebuilds the
+    one-hot.
+    """
+    num_sub, k, sub_dim = centroids.shape
+    grp = _group(num_sub, sub_dim)
+    eye = jnp.eye(grp, dtype=centroids.dtype)
+    outs = []
+    for j in range(0, num_sub, grp):
+        block = (centroids[j:j + grp, :, None, :] * eye[:, None, :, None]
+                 ).reshape(grp, k, grp * sub_dim)
+        p = jax.lax.dot_general(
+            _onehot(codes[:, j:j + grp], k), _bf16_pieces(block),
+            (((1, 2), (0, 1)), ((), ())), preferred_element_type=jnp.float32)
+        outs.append(_sum_pieces(p))
+    return jnp.concatenate(outs, axis=-1).astype(centroids.dtype)
+
+
+def _onehot_decode_fwd(codes, centroids):
+    return _onehot_decode(codes, centroids), (codes, centroids)
+
+
+def _onehot_decode_bwd(res, g_out):
+    """Centroid gradient ``one_hot^T @ g`` per block, ``g`` cut into
+    bf16 pieces as the forward cuts the codebook: it differs from the
+    gather's scatter-add only in the order of its f32 sums."""
+    codes, centroids = res
+    num_sub, k, sub_dim = centroids.shape
+    grp = _group(num_sub, sub_dim)
+    grads = []
+    for j in range(0, num_sub, grp):
+        cols = g_out[:, j * sub_dim:(j + grp) * sub_dim]
+        p = jax.lax.dot_general(
+            _onehot(codes[:, j:j + grp], k), _bf16_pieces(cols),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        full = _sum_pieces(p).reshape(grp, k, grp, sub_dim)
+        grads += [full[i, :, i, :] for i in range(grp)]
+    return None, jnp.stack(grads).astype(centroids.dtype)
+
+
+_onehot_decode.defvjp(_onehot_decode_fwd, _onehot_decode_bwd)
 
 
 def quantize(e: jax.Array, centroids: jax.Array,
@@ -108,17 +203,19 @@ def quantize(e: jax.Array, centroids: jax.Array,
     lead = e.shape[:-1]
     e_sub = e.reshape(lead + (num_sub, sub_dim))
     codes = assign_codes(e_sub, centroids, k_limit)
-    c_sel = decode_codes(codes, centroids)        # (..., D, S)
+    c_sel = decode_codes(codes, centroids)        # (..., d)
     # Straight-through: forward value is the centroid, gradient hits e.
-    q_sub = e_sub + jax.lax.stop_gradient(c_sel - e_sub)
+    q = e + jax.lax.stop_gradient(c_sel - e)
     # Codebook + commitment losses (gradients: codebook term -> centroids
-    # via the differentiable gather in c_sel; commitment -> e).
-    codebook = jnp.mean(jnp.sum(
-        jnp.square(jax.lax.stop_gradient(e_sub) - c_sel), axis=-1))
-    commit = jnp.mean(jnp.sum(
-        jnp.square(e_sub - jax.lax.stop_gradient(c_sel)), axis=-1))
+    # through the decode; commitment -> e), each the mean over the
+    # subvectors of their squared distance.
+    def sub_mean(diff):
+        sq = jnp.square(diff).reshape(lead + (num_sub, sub_dim))
+        return jnp.mean(jnp.sum(sq, axis=-1))
+    codebook = sub_mean(jax.lax.stop_gradient(e) - c_sel)
+    commit = sub_mean(e - jax.lax.stop_gradient(c_sel))
     aux = codebook + beta * commit
-    return q_sub.reshape(e.shape), codes, aux
+    return q, codes, aux
 
 
 # ----------------------------------------------------------------------

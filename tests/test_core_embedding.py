@@ -1,4 +1,6 @@
 """Core DPQ/MGQE correctness + the paper's serving-size accounting."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -74,6 +76,99 @@ def test_dpq_multi_dim_ids(key):
     ids = jnp.zeros((3, 5), jnp.int32)
     out, _ = emb.apply(p, ids)
     assert out.shape == (3, 5, 16)
+
+
+# ------------------------------------------------ codebook decode paths
+
+def _gather_decode(codes, centroids):
+    """The row gather the one-hot decode replaces: codes (..., D) ->
+    (..., D*S)."""
+    num_sub, _, sub_dim = centroids.shape
+    rows = jnp.take_along_axis(
+        centroids[None], codes.reshape(-1, num_sub)[:, :, None, None], axis=2)
+    return rows.reshape(codes.shape[:-1] + (num_sub * sub_dim,))
+
+
+def _decode_case(k, with_limit, n=96, num_sub=5, sub_dim=2):
+    """Rows, random-normal f32 centroids (every mantissa bit used) and
+    per-row budgets (a quarter of K on odd rows) for a K-centroid
+    codebook."""
+    ke, kc = jax.random.split(jax.random.PRNGKey(k + with_limit))
+    e = jax.random.normal(ke, (n, num_sub * sub_dim), jnp.float32)
+    cent = jax.random.normal(kc, (num_sub, k, sub_dim), jnp.float32)
+    limit = None
+    if with_limit:
+        limit = jnp.where(jnp.arange(n) % 2 == 1, max(1, k // 4), k)
+    return e, cent, limit
+
+
+@pytest.mark.parametrize(
+    "k,with_limit,num_sub,sub_dim",
+    [(k, lim, 5, 2) for k in (4, 64, 256, 512) for lim in (False, True)]
+    + [(k, True, n, s) for k in (64, 512) for n, s in ((8, 16), (2, 96))])
+def test_onehot_decode_matches_the_gather(k, with_limit, num_sub, sub_dim,
+                                          monkeypatch):
+    """The one-hot decode is bit-identical to the gather, and the
+    gradients of ``quantize`` (output plus aux loss) match the gather
+    form's but for the order of f32 sums.  The shapes take every
+    grouping of subspaces: all five in one block (d=10 as in deepfm),
+    blocks of two, and one subspace a block for wide rows."""
+    e, cent, limit = _decode_case(k, with_limit, num_sub=num_sub,
+                                  sub_dim=sub_dim)
+    codes = dpq.assign_codes(e.reshape(-1, num_sub, sub_dim), cent, limit)
+    assert int(jnp.max(codes)) > 0
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(dpq.decode_codes)(codes, cent)),
+        np.asarray(_gather_decode(codes, cent)))
+
+    w = jax.random.normal(jax.random.PRNGKey(7), e.shape)
+
+    def loss(e, cent):
+        q, _, aux = dpq.quantize(e, cent, k_limit=limit)
+        return jnp.sum(q * w) + aux
+
+    got = jax.grad(loss, argnums=(0, 1))(e, cent)
+    monkeypatch.setattr(dpq, "decode_codes", _gather_decode)
+    want = jax.grad(loss, argnums=(0, 1))(e, cent)
+    for g, r in zip(got, want):
+        # sums that cancel leave a relative gap of order n * 2^-24 on
+        # their smallest entries; the floor is 1e-6 of the largest
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-6,
+                                   atol=1e-6 * float(jnp.max(jnp.abs(r))))
+
+
+@pytest.mark.parametrize("k", [256, 512])
+def test_decode_is_a_product_at_every_codebook_size(k):
+    """The decode and its gradient are products on the centroids, with
+    no gather or scatter, within and beyond the uint8 code range."""
+    _, cent, _ = _decode_case(k, False)
+    codes = jnp.zeros((96, 5), jnp.int32)
+    text = str(jax.make_jaxpr(jax.vjp(dpq.decode_codes, codes, cent)[1])(
+        jnp.ones((96, 10), jnp.float32)))
+    text += str(jax.make_jaxpr(dpq.decode_codes)(codes, cent))
+    assert "dot_general" in text, text
+    assert "gather" not in text and "scatter" not in text, text
+
+
+def test_quantize_keeps_codes_not_the_onehot_for_its_gradient():
+    """Across the forward/backward boundary ``quantize`` keeps the int
+    codes; no (B, D*K) or (B, D, K) one-hot is a residual."""
+    n, num_sub, k = 64, 5, 256
+    e, cent, limit = _decode_case(k, True, n=n, num_sub=num_sub)
+
+    def loss(e, cent):
+        q, _, aux = dpq.quantize(e, cent, k_limit=limit)
+        return jnp.sum(q) + aux
+
+    _, vjp = jax.vjp(jax.jit(loss), e, cent)
+    kept = {(x.shape, str(x.dtype)) for x in jax.tree.leaves(vjp)}
+    assert ((n, num_sub), "int32") in kept, kept
+    assert not any(s in ((n, num_sub * k), (n, num_sub, k)) for s, _ in kept)
+    assert sum(math.prod(s) for s, _ in kept) < n * num_sub * k
+    # the decode's own gradient keeps its inputs, the codes among them
+    codes = dpq.assign_codes(e.reshape(n, num_sub, 2), cent, limit)
+    _, res = dpq._onehot_decode_fwd(codes, cent)
+    assert res[0] is codes
 
 
 # ---------------------------------------------------------------- MGQE

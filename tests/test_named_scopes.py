@@ -102,6 +102,21 @@ def test_deepfm_gathers_and_scatters_sit_in_their_layer(deepfm_step):
     # one first-order table per field, each gathered and scattered into
     assert gathers["first_order"] == scatters["first_order.bwd"] \
         == cfg.n_sparse
+    # one table per field, its rows gathered and its gradient scattered;
+    # the codebooks are decoded by products, so no gather or scatter
+    # touches a codebook (the other gathers under ``fields`` read the
+    # MGQE tier's centroid budget, one int per id)
+    n_mgqe = sum(v >= cfg.mgqe_min_vocab for v in cfg.field_vocab_sizes)
+    rows = [i for i in everywhere(comps, "gather")
+            if op_scope(i) == "fields" and i.shape.startswith("f32")]
+    assert len(rows) == cfg.n_sparse
+    assert {re.sub(r"\{.*", "", i.shape) for i in rows} == {
+        f"f32[256,1,{cfg.embed_dim}]"}
+    assert gathers["fields"] - len(rows) == n_mgqe
+    assert scatters["fields.bwd"] == cfg.n_sparse
+    assert all(re.match(rf"f32\[\d+,{cfg.embed_dim}\]", i.shape)
+               for i in everywhere(comps, "scatter")
+               if op_scope(i) == "fields.bwd")
     with_scatter = {c for c, body in comps.items()
                     if any(i.opcode == "scatter" for i in body)}
     fusions = [i for body in comps.values() for i in body
@@ -112,15 +127,23 @@ def test_deepfm_gathers_and_scatters_sit_in_their_layer(deepfm_step):
 
 
 def test_deepfm_dots_are_the_tower_and_the_assign(deepfm_step):
-    """Every dot is the tower's (forward and gradient) except the MGQE
-    assign's distance product, one per quantized field, under
-    ``fields`` (the straight-through gradient has none)."""
+    """Every dot is the tower's (forward and gradient) except, per
+    quantized field, the MGQE assign's distance product and the
+    codebook decode's one-hot product under ``fields``, and the
+    decode's gradient product under ``fields.bwd`` (the
+    straight-through gradient has none)."""
     cfg, (comps, _) = deepfm_step
     dots = Counter(op_scope(i) for i in everywhere(comps, "dot"))
     n_mgqe = sum(v >= cfg.mgqe_min_vocab for v in cfg.field_vocab_sizes)
-    assert set(dots) == {"tower", "tower.bwd", "fields"}, dots
-    assert dots["fields"] == n_mgqe
+    assert set(dots) == {"tower", "tower.bwd", "fields", "fields.bwd"}, dots
+    assert dots["fields"] == 2 * n_mgqe
+    assert dots["fields.bwd"] == n_mgqe
     assert dots["tower"] >= len(cfg.mlp_dims) + 1
+    nested = Counter(scope(op_name(i), depth=2)
+                     for i in everywhere(comps, "dot")
+                     if op_scope(i).startswith("fields"))
+    assert nested["fields/codebook"] == nested["fields/codebook.bwd"] \
+        == n_mgqe, nested
 
 
 def test_deepfm_adagrad_update_sits_under_optimizer(deepfm_step):
@@ -171,7 +194,9 @@ def test_autoint_tower_scope():
     comps, _ = parse(compiled_step(AutoInt(cfg), 16, cfg.n_sparse))
     dots = Counter(op_scope(i) for i in everywhere(comps, "dot"))
     assert {"tower", "tower.bwd"} <= set(dots) <= {"tower", "tower.bwd",
-                                                  "fields"}, dots
+                                                  "fields", "fields.bwd"}, dots
+    # per quantized field: assign and decode, and the decode's gradient
+    assert dots["fields"] == 2 * dots["fields.bwd"], dots
 
 
 def test_deepfm_serve_scopes():
@@ -195,6 +220,7 @@ def test_deepfm_serve_scopes():
     ("jit(step)/jvp(fields)/jit(_take)/gather", "fields"),
     ("jit(step)/transpose(jvp(fields))/jit(_take)/scatter-add", "fields.bwd"),
     ("jit(step)/jit(main)/optimizer/adagrad/sqrt", "optimizer"),
+    ("jit(step)/transpose(jvp(fields))/codebook/dot_general", "fields.bwd"),
     ("jit(step)/jvp()/mul", ""),
     ("reduce_sum", ""),
     ("", ""),
