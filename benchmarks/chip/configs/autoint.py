@@ -59,3 +59,18 @@ def train_loss(params, batch, model: Mapping, prec: R.Prec):
     x, aux = R.train_fields(params["fields"], batch["sparse_ids"], model,
                             prec)
     return R.bce(_logit(params, x, model, prec), batch["label"]) + aux
+
+
+def forward_flops_per_example(model: Mapping) -> int:
+    """The dense part of the forward pass per example, a multiply-add
+    counted as 2 (embedding lookups are bytes, not operations): per
+    interacting layer the Q, K, V and residual projections, QK^T and
+    the attention-weighted sum of V; then the output unit."""
+    f, h, da = model["n_sparse"], model["n_attn_heads"], model["d_attn"]
+    out = h * da
+    d_in, total = model["embed_dim"], 0
+    for _ in range(model["n_attn_layers"]):
+        total += 4 * 2 * f * d_in * out       # wq, wk, wv, wres
+        total += 2 * 2 * h * f * f * da       # scores and probs @ v
+        d_in = out
+    return total + 2 * f * out

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 import reference as R
+import work
 
 
 def make_params(key, model: Mapping) -> Dict:
@@ -62,3 +63,14 @@ def train_loss(params, batch, model: Mapping, prec: R.Prec):
                             prec)
     logits = _logit(params, x, batch["sparse_ids"], prec)
     return R.bce(logits, batch["label"]) + aux
+
+
+def forward_flops_per_example(model: Mapping) -> int:
+    """The dense part of the forward pass per example, a multiply-add
+    counted as 2 (embedding lookups are bytes, not operations): the FM
+    second-order term (sum, squares, sum of squares, the final combine
+    over d) + the MLP over the concatenated fields."""
+    f, d = model["n_sparse"], model["embed_dim"]
+    fm = 3 * f * d + 3 * d
+    mlp = work.mlp_flops((f * d,) + tuple(model["mlp_dims"]) + (1,))
+    return fm + mlp

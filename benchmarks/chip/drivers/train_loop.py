@@ -10,7 +10,9 @@ comparison needs from them is read there.  The window then goes on
 with the same state: each step uploads its batch and calls the step,
 then waits for the previous step's loss.  The rate is the examples of
 every step completed over the time from the window's start to the last
-completion.
+completion.  The window's stats count ``steps``; the device ran
+``steps`` + 1, the first dispatched before the loop.  ``programs()``
+gives a traced run the step's text, for the trace's named scopes.
 """
 from __future__ import annotations
 
@@ -95,6 +97,25 @@ class Session:
                         n * self.batch / (done - t0)},
             "stats": {"steps": n, "window_s": done - t0},
         }
+
+    def programs(self):
+        """The optimized HLO text of the window's step, compiled again
+        with the persistent cache off for this one compile: a step read
+        back from that cache carries no ``op_name`` metadata on a v5e.
+        JAX's in-memory caches would hand back the executable the window
+        ran, so they are cleared first; nothing runs the step after."""
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+        x = jax.device_put(self.ring[0])
+        was = jax.config.jax_enable_compilation_cache
+        jax.clear_caches()
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            return [self.step_fn.lower(self.state, x).compile().as_text()]
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
 
     def free(self):
         H.delete(self.state)

@@ -1,6 +1,6 @@
 """Model FLOP/s utilization of offline scoring, in %: forward FLOPs per
-example (``work.forward_flops_per_example``) x the traced run's
-``score_examples_per_s`` over the chip's bf16 peak."""
+example (the configuration's ``forward_flops_per_example``) x the
+traced run's ``score_examples_per_s`` over the chip's bf16 peak."""
 
 
 def read(rec):

@@ -81,10 +81,30 @@ def small_traffic(name: str):
     return t
 
 
-def run_small(spec, workload, seed=3, seconds=0.5, **kw):
+def run_small(spec, workload, seed=3, seconds=0.5, trace=False, **kw):
     import run
     cell = {w["name"]: w for w in spec["workloads"]}[workload]
-    return run.run(copy.deepcopy(spec), workload, seed, seconds, False,
+    return run.run(copy.deepcopy(spec), workload, seed, seconds, trace,
                    require_tpu=False, config=small_config(cell["config"]),
                    traffic=small_traffic(cell["traffic"]), peaks=CPU_PEAKS,
                    **kw)
+
+
+def with_device_ops(monkeypatch):
+    """A CPU trace has no device plane: add one op, 10 ns, for each
+    scope the programs' texts name, inside the window, before the trace
+    is reduced."""
+    import harness as H
+    T = H.load_module("trace")
+    real = T.reduce
+
+    def reduce(events, patterns=(), op_names=None):
+        lo, _ = T.window_of(events)
+        one = {}
+        for name, path in sorted((op_names or {}).items()):
+            one.setdefault(T.scope_of(path), name)
+        ops = [T.Event("/device:TPU:0", "XLA Ops", f"%{name} = f32[1] op()",
+                       lo + 10 * i, 10.0)
+               for i, name in enumerate(one.values())]
+        return real(list(events) + ops, patterns, op_names)
+    monkeypatch.setattr(T, "reduce", reduce)

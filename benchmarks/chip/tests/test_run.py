@@ -10,6 +10,7 @@ import jax
 import pytest
 
 import conftest as C
+import harness as H
 
 SERVING = ["deepfm.ctr_online", "autoint.ctr_online", "autoint.ctr_bulk"]
 
@@ -84,6 +85,88 @@ def test_refused_with_backend_env(var, monkeypatch):
     monkeypatch.setenv(var, "xla")
     assert run.main(["--workload", "deepfm.ctr_online", "--seed", "1",
                      "--seconds", "1"]) == 2
+
+
+@pytest.mark.parametrize("env", ["REPRO_KERNEL_BACKEND",
+                                 "REPRO_KERNEL_TUNE_CACHE"])
+def test_traced_run_refused_with_the_dispatch_steered(env, monkeypatch,
+                                                      capsys):
+    import run
+    monkeypatch.setenv(env, "xla")
+    assert run.main(["--workload", "deepfm.train", "--seed", "7",
+                     "--seconds", "1", "--trace", "1"]) == 2
+    assert "refused" in capsys.readouterr().err
+
+
+def test_traced_run_refused_off_the_chip(spec, monkeypatch, capsys):
+    """Off a TPU no split is measured, and nothing is set up."""
+    import run
+    for var in run.REFUSED_ENV:
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(H.load_module("drivers", "train_loop"), "setup",
+                        _never_set_up)
+    assert run.main(["--workload", "deepfm.train", "--seed", "7",
+                     "--seconds", "1", "--trace", "1"]) == 2
+    assert "not 'tpu'" in capsys.readouterr().err
+    wide = dict(spec, workloads=[dict(w, chips=99)
+                                 for w in spec["workloads"]])
+    with pytest.raises(run.Refused, match="needs 99"):
+        run.run(wide, "deepfm.train", 7, 1.0, True, require_tpu=False)
+
+
+def _never_set_up(ctx):
+    raise AssertionError("set up after a refusal")
+
+
+def test_configuration_without_its_work_is_refused(spec, monkeypatch):
+    """Refused before set-up, naming the file, not after the window."""
+    import run
+    ref = H.load_module("configs", "deepfm")
+    monkeypatch.delattr(ref, "forward_flops_per_example")
+    monkeypatch.setattr(H.load_module("drivers", "train_loop"), "setup",
+                        _never_set_up)
+    for trace in (False, True):
+        with pytest.raises(run.Refused, match="configs/deepfm.py"):
+            C.run_small(spec, "deepfm.train", trace=trace)
+
+
+def test_traced_train_run_reads_the_steps_scopes(spec, monkeypatch):
+    """The train driver's step text, compiled again after the window,
+    names every layer; each per-layer metric of the cell is reported."""
+    C.with_device_ops(monkeypatch)
+    out = C.run_small(spec, "deepfm.train", trace=True)
+    assert out["correct"] is True, out["checks"]
+    want = {m["name"] for m in spec["per_layer"]
+            if "deepfm.train" in m.get("workloads", ["deepfm.train"])}
+    assert set(out["metrics"]) == want
+    device = ("fields_fwd_ms.train", "fields_bwd_ms.train",
+              "first_order_ms.train", "tower_ms.train",
+              "optimizer_ms.train", "unscoped_ms.train")
+    ms = {k: out["metrics"][k]["value"] for k in device}
+    # one 10 ns op per scope; the first order, tower and optimizer are
+    # read in both directions
+    assert ms["tower_ms.train"] == pytest.approx(2 * ms["fields_fwd_ms.train"])
+    assert ms["fields_fwd_ms.train"] > 0
+    assert out["metrics"]["compiles.train"]["value"] >= 1
+    assert out["device"]["busy_s"] > 0
+
+
+def test_costliest_compiles():
+    import run
+    rec = lambda n, s, h=0, m=0: {"compiles": n, "compile_s": s,
+                                  "traces": n, "trace_s": 0.1,
+                                  "cache_hits": h, "cache_misses": m}
+    stats = {"jit(step)": rec(1, 90.0, m=1),
+             "jit(broadcast_in_dim)": rec(40, 4.0, h=40),
+             "jit(_take)": rec(0, 0.0)}
+    assert run.total(stats, "compiles") == 41
+    assert run.total(stats, "compile_s") == 94.0
+    assert run.costliest(stats) == [
+        ["jit(step)", 1, 90.0, 1, 0.1, 0, 1],
+        ["jit(broadcast_in_dim)", 40, 4.0, 40, 0.1, 40, 0]]
+    before = {"jit(step)": rec(1, 90.0, m=1), "jit(_take)": rec(0, 0.0)}
+    assert run.since(stats, before) == {
+        "jit(broadcast_in_dim)": rec(40, 4.0, h=40)}
 
 
 def test_no_result_without_the_program(tmp_path):

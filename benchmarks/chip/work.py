@@ -5,9 +5,11 @@ from shapes, the same whatever implements them.
   ``mgqe_decode`` call: the gathered codes read, the centroid table
   read once, the decoded rows written.  A fused or a split decode is
   read against the same count.
-* :func:`forward_flops_per_example` — the dense part of a CTR model's
-  forward pass per example, counting a multiply-add as 2 operations.
-  Embedding lookups are not counted (they are bytes, not operations).
+* :func:`mlp_flops` — the operations of a dense stack's products.
+
+Each configuration states its own forward operations per example in
+its reference, ``configs/<config>.py``, as
+``forward_flops_per_example(model)``; these are what several share.
 """
 from __future__ import annotations
 
@@ -40,34 +42,7 @@ def mgqe_decode_bytes(batch: int, num_subspaces: int, num_centroids: int,
             + batch * d * value_bytes)
 
 
-def _mlp_flops(dims) -> int:
+def mlp_flops(dims) -> int:
+    """Products of a dense stack of widths ``dims``, a multiply-add
+    counted as 2 operations."""
     return sum(2 * a * b for a, b in zip(dims, dims[1:]))
-
-
-def deepfm_flops(model: Mapping) -> int:
-    """FM second-order term (sum, squares, sum of squares, the final
-    combine over d) + the MLP over the concatenated fields."""
-    f, d = model["n_sparse"], model["embed_dim"]
-    fm = 3 * f * d + 3 * d
-    mlp = _mlp_flops((f * d,) + tuple(model["mlp_dims"]) + (1,))
-    return fm + mlp
-
-
-def autoint_flops(model: Mapping) -> int:
-    """Per interacting layer: the Q, K, V and residual projections,
-    QK^T and the attention-weighted sum of V; then the output unit."""
-    f, h, da = model["n_sparse"], model["n_attn_heads"], model["d_attn"]
-    out = h * da
-    d_in, total = model["embed_dim"], 0
-    for _ in range(model["n_attn_layers"]):
-        total += 4 * 2 * f * d_in * out       # wq, wk, wv, wres
-        total += 2 * 2 * h * f * f * da       # scores and probs @ v
-        d_in = out
-    return total + 2 * f * out
-
-
-FLOPS = {"deepfm": deepfm_flops, "autoint": autoint_flops}
-
-
-def forward_flops_per_example(model: Mapping) -> int:
-    return FLOPS[model["model"]](model)
